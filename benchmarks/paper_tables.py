@@ -251,8 +251,8 @@ def kernels() -> List[Row]:
     from repro.kernels.decode_attention.kernel import (
         paged_decode_attention_kernel)
     from repro.kernels.decode_attention.ref import paged_decode_attention_ref
-    kp = kd.reshape(-1, 32, 2, 64)     # 2*16 pages of 32 tokens
-    vp = vd.reshape(-1, 32, 2, 64)
+    kp = kd.reshape(-1, 32, 2, 64).swapaxes(1, 2)   # 2*16 head-major pages
+    vp = vd.reshape(-1, 32, 2, 64).swapaxes(1, 2)
     tables = jnp.arange(32, dtype=jnp.int32).reshape(2, 16)
     us, out = _timeit(lambda: paged_decode_attention_kernel(
         qd, kp, vp, tables, lens, interpret=True), n=1)

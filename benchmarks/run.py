@@ -11,6 +11,7 @@ import sys
 
 from benchmarks import extensions as E
 from benchmarks import paper_tables as T
+from repro.compile_cache import enable_compile_cache
 
 SUITES = {
     "table1": lambda fast: T.table1_correlation(60 if fast else 150),
@@ -77,6 +78,7 @@ def main() -> None:
                     help=f"comma-separated subset of {sorted(SUITES)}")
     ap.add_argument("--fast", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     names = (args.only.split(",") if args.only else list(SUITES))
     print("name,us_per_call,derived")
     failures = 0

@@ -127,7 +127,7 @@ def _paged_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(k_start < length)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32) * scale         # [G, D]
-        k = k_ref[0, :, 0].astype(jnp.float32)              # [bt, D]
+        k = k_ref[0, 0].astype(jnp.float32)                 # [bt, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [G, bt]
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -138,7 +138,7 @@ def _paged_kernel(tables_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         alpha = jnp.exp(m_prev - m_new)
         l_ref[:, 0] = l_prev * alpha + p.sum(axis=1)
         m_ref[:, 0] = m_new
-        pv = jax.lax.dot_general(p, v_ref[0, :, 0].astype(jnp.float32),
+        pv = jax.lax.dot_general(p, v_ref[0, 0].astype(jnp.float32),
                                  (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
@@ -189,13 +189,13 @@ def _prefix_prefill_kernel(tables_ref, plen_ref, slen_ref, q_ref, ks_ref,
     @pl.when((ji < mb) & (k_start < plen))
     def _prefix_block():
         q = q_ref[0, 0].astype(jnp.float32) * scale         # [S*G, D]
-        k = kp_ref[0, :, 0].astype(jnp.float32)             # [bt, D]
+        k = kp_ref[0, 0].astype(jnp.float32)                # [bt, D]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(k_pos < plen, s, NEG_INF)
         p, alpha = _update(s)
-        pv = jax.lax.dot_general(p, vp_ref[0, :, 0].astype(jnp.float32),
+        pv = jax.lax.dot_general(p, vp_ref[0, 0].astype(jnp.float32),
                                  (((1,), (0,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         acc_ref[...] = acc_ref[...] * alpha[:, None] + pv
@@ -228,12 +228,12 @@ def paged_prefix_prefill_attention_kernel(
         prefix_lens: jax.Array, suffix_lens: jax.Array, *,
         interpret: bool = False) -> jax.Array:
     """q, k_suf, v_suf: [B, S, H*, D] suffix tensors (rope'd at absolute
-    positions); pages: [num_blocks, block_tokens, Hkv, D];
+    positions); pages: [num_blocks, Hkv, block_tokens, D];
     block_tables: [B, MB] physical ids of each request's prefix pages
     (pad entries must be valid ids — masked but still indexed);
     prefix_lens/suffix_lens: [B] -> [B, S, Hq, D]."""
     b, s, hq, d = q.shape
-    _, bt, hkv, _ = k_pages.shape
+    _, hkv, bt, _ = k_pages.shape
     mb = block_tables.shape[1]
     g = hq // hkv
 
@@ -267,12 +267,12 @@ def paged_prefix_prefill_attention_kernel(
                          lambda bi, hi, ji, tables, pl_, sl: (bi, hi, 0, 0)),
             pl.BlockSpec((1, 1, s, d),
                          lambda bi, hi, ji, tables, pl_, sl: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bt, 1, d),
+            pl.BlockSpec((1, 1, bt, d),
                          lambda bi, hi, ji, tables, pl_, sl:
-                         (_page_index(ji, tables, pl_, bi), 0, hi, 0)),
-            pl.BlockSpec((1, bt, 1, d),
+                         (_page_index(ji, tables, pl_, bi), hi, 0, 0)),
+            pl.BlockSpec((1, 1, bt, d),
                          lambda bi, hi, ji, tables, pl_, sl:
-                         (_page_index(ji, tables, pl_, bi), 0, hi, 0)),
+                         (_page_index(ji, tables, pl_, bi), hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, s * g, d),
                                lambda bi, hi, ji, tables, pl_, sl:
@@ -299,12 +299,12 @@ def paged_decode_attention_kernel(q: jax.Array, k_pages: jax.Array,
                                   v_pages: jax.Array, block_tables: jax.Array,
                                   lengths: jax.Array, *,
                                   interpret: bool = False) -> jax.Array:
-    """q: [B, Hq, D]; pages: [num_blocks, block_tokens, Hkv, D];
+    """q: [B, Hq, D]; pages: [num_blocks, Hkv, block_tokens, D];
     block_tables: [B, max_blocks] physical block ids (pad entries must be
     valid ids — they are masked, but still indexed); lengths: [B]
     -> [B, Hq, D]."""
     b, hq, d = q.shape
-    _, bt, hkv, _ = k_pages.shape
+    _, hkv, bt, _ = k_pages.shape
     max_blocks = block_tables.shape[1]
     g = hq // hkv
 
@@ -316,12 +316,12 @@ def paged_decode_attention_kernel(q: jax.Array, k_pages: jax.Array,
         in_specs=[
             pl.BlockSpec((1, 1, g, d),
                          lambda bi, hi, ji, tables, lens: (bi, hi, 0, 0)),
-            pl.BlockSpec((1, bt, 1, d),
+            pl.BlockSpec((1, 1, bt, d),
                          lambda bi, hi, ji, tables, lens:
-                         (tables[bi, ji], 0, hi, 0)),
-            pl.BlockSpec((1, bt, 1, d),
+                         (tables[bi, ji], hi, 0, 0)),
+            pl.BlockSpec((1, 1, bt, d),
                          lambda bi, hi, ji, tables, lens:
-                         (tables[bi, ji], 0, hi, 0)),
+                         (tables[bi, ji], hi, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d),
                                lambda bi, hi, ji, tables, lens:

@@ -1,4 +1,9 @@
-"""jit'd public wrapper: TPU pallas kernel, interpret-mode elsewhere."""
+"""jit'd public wrappers.  The paged kernels (the serving path) choose
+between the Pallas kernel and the gather oracle by the platform the
+program is *lowered* for (``lax.platform_dependent``): a TPU program
+always holds the kernel, a CPU program always the oracle, whatever
+device the process defaults to.  The dense kernels run in interpret mode
+off the TPU."""
 from __future__ import annotations
 
 import functools
@@ -35,11 +40,12 @@ def paged_decode_attention_impl(q, k_pages, v_pages, block_tables, lengths,
     entry per (batch shape, pool shape, window length) — instead of
     paying a nested jit-cache lookup per inner step and per trace.
     Direct (eager) callers should use :func:`paged_decode_attention`."""
-    if use_ref or jax.devices()[0].platform != "tpu":
-        return paged_decode_attention_ref(q, k_pages, v_pages,
-                                          block_tables, lengths)
-    return paged_decode_attention_kernel(q, k_pages, v_pages, block_tables,
-                                         lengths)
+    args = (q, k_pages, v_pages, block_tables, lengths)
+    if use_ref:
+        return paged_decode_attention_ref(*args)
+    return jax.lax.platform_dependent(*args,
+                                      tpu=paged_decode_attention_kernel,
+                                      default=paged_decode_attention_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("use_ref",))
@@ -47,9 +53,9 @@ def paged_decode_attention_impl(q, k_pages, v_pages, block_tables, lengths,
 def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            use_ref: bool = False):
     """Block-table paged decode attention (shared page pool; per-request
-    tables).  ``use_ref`` or any non-TPU backend falls back to the
-    gather-based oracle — the Pallas path only pays off when the pool
-    lives in HBM and the tables keep the DMA set small."""
+    tables).  ``use_ref`` or a program lowered for any platform but the
+    TPU takes the gather-based oracle — the Pallas path only pays off
+    when the pool lives in HBM and the tables keep the DMA set small."""
     return paged_decode_attention_impl(q, k_pages, v_pages, block_tables,
                                        lengths, use_ref=use_ref)
 
@@ -68,13 +74,13 @@ def paged_prefix_prefill_attention_impl(q, k_suf, v_suf, k_pages, v_pages,
     :func:`paged_decode_attention_impl`: the jit cache stays keyed at the
     engine's entry point).  Direct callers should use
     :func:`paged_prefix_prefill_attention`."""
-    if use_ref or jax.devices()[0].platform != "tpu":
-        return paged_prefix_prefill_attention_ref(
-            q, k_suf, v_suf, k_pages, v_pages, block_tables, prefix_lens,
+    args = (q, k_suf, v_suf, k_pages, v_pages, block_tables, prefix_lens,
             suffix_lens)
-    return paged_prefix_prefill_attention_kernel(
-        q, k_suf, v_suf, k_pages, v_pages, block_tables, prefix_lens,
-        suffix_lens)
+    if use_ref:
+        return paged_prefix_prefill_attention_ref(*args)
+    return jax.lax.platform_dependent(
+        *args, tpu=paged_prefix_prefill_attention_kernel,
+        default=paged_prefix_prefill_attention_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("use_ref",))
@@ -83,8 +89,9 @@ def paged_prefix_prefill_attention(q, k_suf, v_suf, k_pages, v_pages,
                                    block_tables, prefix_lens, suffix_lens,
                                    *, use_ref: bool = False):
     """Suffix-prefill attention against cached prefix pages (shared
-    instruction KV; per-request tables).  ``use_ref`` or any non-TPU
-    backend falls back to the gather-based oracle."""
+    instruction KV; per-request tables).  ``use_ref`` or a program
+    lowered for any platform but the TPU takes the gather-based
+    oracle."""
     return paged_prefix_prefill_attention_impl(
         q, k_suf, v_suf, k_pages, v_pages, block_tables, prefix_lens,
         suffix_lens, use_ref=use_ref)

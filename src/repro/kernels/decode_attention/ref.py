@@ -11,15 +11,21 @@ def paged_decode_attention_ref(q: jax.Array, k_pages: jax.Array,
                                v_pages: jax.Array, block_tables: jax.Array,
                                lengths: jax.Array) -> jax.Array:
     """Gather-based oracle for the block-table kernel: pages
-    [num_blocks, block_tokens, Hkv, D] are gathered through
+    [num_blocks, Hkv, block_tokens, D] are gathered through
     ``block_tables`` [B, max_blocks] into a dense [B, S, Hkv, D] view and
     fed to the dense oracle.  S = max_blocks * block_tokens; positions
     past ``lengths`` (including whole pad-table pages) are masked."""
-    b, hq, d = q.shape
-    _, bt, hkv, _ = k_pages.shape
-    k = k_pages[block_tables].reshape(b, -1, hkv, d)
-    v = v_pages[block_tables].reshape(b, -1, hkv, d)
-    return decode_attention_ref(q, k, v, lengths)
+    return decode_attention_ref(q, gather_dense(k_pages, block_tables),
+                                gather_dense(v_pages, block_tables), lengths)
+
+
+def gather_dense(pages: jax.Array, block_tables: jax.Array) -> jax.Array:
+    """Head-major pages [num_blocks, Hkv, block_tokens, D] gathered
+    through ``block_tables`` [B, M] into the dense token-major view
+    [B, M * block_tokens, Hkv, D]."""
+    b = block_tables.shape[0]
+    _, hkv, _, d = pages.shape
+    return pages[block_tables].transpose(0, 1, 3, 2, 4).reshape(b, -1, hkv, d)
 
 
 def paged_prefix_prefill_attention_ref(
@@ -38,10 +44,10 @@ def paged_prefix_prefill_attention_ref(
     or ``j - P <= i`` and ``j - P < suffix_lens[b]`` (suffix part, P the
     gathered prefix capacity).  Returns [B, S, Hq, D]."""
     b, s, hq, d = q.shape
-    _, bt, hkv, _ = k_pages.shape
+    hkv = k_pages.shape[1]
     g = hq // hkv
-    kp = k_pages[block_tables].reshape(b, -1, hkv, d)
-    vp = v_pages[block_tables].reshape(b, -1, hkv, d)
+    kp = gather_dense(k_pages, block_tables)
+    vp = gather_dense(v_pages, block_tables)
     p_cap = kp.shape[1]
     k_cat = jnp.concatenate([kp, k_suf], axis=1).astype(jnp.float32)
     v_cat = jnp.concatenate([vp, v_suf], axis=1).astype(jnp.float32)

@@ -2,16 +2,23 @@
 
 Two backends:
   --backend sim    : roofline-cost cluster simulator at paper scale
-  --backend engine : the real JAX engine on a reduced config (CPU)
+  --backend engine : the real JAX engine, on a reduced f32 config by
+                     default (CPU-sized); paged strategies serve the
+                     published widths with ``--full-width`` and bf16
+                     weights and KV with ``--dtype bfloat16`` (a TPU)
 
     PYTHONPATH=src python -m repro.launch.serve --arch chatglm-6b \
         --strategy magnus --rate 8 --duration 60
+    PYTHONPATH=src python -m repro.launch.serve --arch smollm-135m \
+        --backend engine --strategy magnus-paged --prefix-cache \
+        --full-width --dtype bfloat16 --rate 3 --duration 3
 """
 from __future__ import annotations
 
 import argparse
 import json
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import get_config
 from repro.serving.cost_model import TPU_V5E, V100_32G
 from repro.sim.runner import run_strategy
@@ -68,8 +75,10 @@ def run_paged_engine_backend(arch: str, rate: float, duration: float,
                              spec_decode: bool = False,
                              draft_k: int = 4,
                              checkpoint_dir: str | None = None,
-                             snapshot_every: int = 8) -> dict:
-    """Continuous paged serving for real on CPU: MagnusService drives
+                             snapshot_every: int = 8,
+                             full_width: bool = False,
+                             dtype: str = "float32") -> dict:
+    """Continuous paged serving for real: MagnusService drives
     admission (prediction + block accounting) against the same
     BlockAllocator the engine stores KV pages in (DESIGN.md §8).  The
     engine admits whole scheduler batches as single-dispatch variable-
@@ -91,18 +100,28 @@ def run_paged_engine_backend(arch: str, rate: float, duration: float,
     snapshot lands every ``snapshot_every`` windows, and on start a
     surviving journal from a previous process is recovered first
     (outstanding requests finished bit-exact) before new traffic is
-    served."""
+    served.  ``full_width`` serves the published config instead of its
+    ``reduced()`` CPU variant; ``dtype`` is the weights' and KV's
+    serving dtype.  The service plans against exactly the pool the
+    engine allocates.  ``off_script`` counts requests whose stream is
+    not ``min(gen_length, max_gen)`` tokens long (shed or lost ones
+    included); the engine must drain."""
     import os
     import time
 
+    import jax.numpy as jnp
+
     from repro.core.magnus import MagnusConfig, MagnusService
     from repro.core.predictor import GenerationLengthPredictor
-    from repro.core.wma import MemoryModel
     from repro.serving.engine import PagedContinuousEngine, drive_paged
     from repro.serving.paged_cache import BlockAllocator, MispredictionEWMA
 
-    cfg = get_config(arch).reduced()
-    memory = MemoryModel(cfg, hbm_bytes=2 * 2 ** 30, max_len=200, max_gen=32)
+    cfg = get_config(arch)
+    if not full_width:
+        cfg = cfg.reduced()
+    jdtype = jnp.dtype(dtype)
+    memory = _pool_memory_model(cfg, num_blocks * block_tokens,
+                               jdtype.itemsize, max_len=200, max_gen=32)
     allocator = BlockAllocator(num_blocks, block_tokens)
     predictor = GenerationLengthPredictor(seed=seed).fit(
         make_dataset(60, seed=seed + 1))
@@ -113,7 +132,7 @@ def run_paged_engine_backend(arch: str, rate: float, duration: float,
     ewma = MispredictionEWMA()
     svc.memory.headroom = ewma
     engine = PagedContinuousEngine(cfg, max_concurrency=max_concurrency,
-                                   max_len=200, max_gen=32,
+                                   max_len=200, max_gen=32, dtype=jdtype,
                                    allocator=allocator,
                                    prefix_cache=svc.prefix_cache or False,
                                    mispredict=ewma,
@@ -138,7 +157,7 @@ def run_paged_engine_backend(arch: str, rate: float, duration: float,
             # (the service's allocator belongs to THIS run)
             return PagedContinuousEngine(
                 cfg, max_concurrency=max_concurrency, max_len=200,
-                max_gen=32,
+                max_gen=32, dtype=jdtype,
                 allocator=BlockAllocator(num_blocks, block_tokens),
                 prefix_cache=prefix_cache, default_ttl=ttl_steps,
                 swap_blocks=swap_blocks)
@@ -173,9 +192,13 @@ def run_paged_engine_backend(arch: str, rate: float, duration: float,
     wall = time.perf_counter() - start
     if recovery is not None:
         recovery.close()
+    engine.assert_drained()
     util = st["util"]
     total_tokens = sum(len(g) for g in engine.generated.values())
+    off_script = sum(len(engine.generated.get(r.req_id, ()))
+                     != min(r.gen_length, engine.max_gen) for r in wl)
     return {"requests": st["served"], "steps": st["steps"],
+            "off_script": off_script,
             "wall_s": round(wall, 2),
             "token_tp": round(total_tokens / max(wall, 1e-9), 1),
             "peak_concurrency": st["peak"], "evictions": st["evictions"],
@@ -218,6 +241,18 @@ def run_paged_engine_backend(arch: str, rate: float, duration: float,
             "replayed_reprefill_tokens": st["replayed_reprefill_tokens"],
             "recovered_on_start": recovered,
             "headroom": ewma.snapshot()}
+
+
+def _pool_memory_model(cfg, pool_tokens: int, dtype_bytes: int, *,
+                      max_len: int, max_gen: int):
+    """A :class:`MemoryModel` whose Θ is exactly ``pool_tokens`` of KV
+    at ``dtype_bytes``: the weights plus the engine's pool are the whole
+    device, all of it plannable (the pool is already the reserve)."""
+    from repro.core.wma import MemoryModel
+    pool = pool_tokens * cfg.kv_bytes_per_token(dtype_bytes)
+    return MemoryModel(cfg, hbm_bytes=cfg.param_count() * dtype_bytes + pool,
+                       reserve_frac=1.0, max_len=max_len, max_gen=max_gen,
+                       dtype_bytes=dtype_bytes, param_dtype_bytes=dtype_bytes)
 
 
 def main() -> None:
@@ -266,8 +301,15 @@ def main() -> None:
     ap.add_argument("--snapshot-every", type=int, default=8,
                     help="windows between full engine snapshots when "
                          "--checkpoint-dir is set")
+    ap.add_argument("--full-width", action="store_true",
+                    help="paged engine: serve the published config, not "
+                         "its reduced() CPU-sized variant")
+    ap.add_argument("--dtype", default="float32",
+                    choices=["float32", "bfloat16"],
+                    help="paged engine: serving dtype of weights and KV")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.backend == "engine":
         if args.strategy.endswith("-paged"):
@@ -281,7 +323,9 @@ def main() -> None:
                                            spec_decode=args.spec_decode,
                                            draft_k=args.draft_k,
                                            checkpoint_dir=args.checkpoint_dir,
-                                           snapshot_every=args.snapshot_every)
+                                           snapshot_every=args.snapshot_every,
+                                           full_width=args.full_width,
+                                           dtype=args.dtype)
         else:
             out = run_engine_backend(args.arch, args.rate, args.duration,
                                      args.strategy, args.seed)

@@ -93,7 +93,6 @@ def gqa_decode_attention_cp(q: jax.Array, k_cache: jax.Array,
     of [B, H, D]-sized tensors — the TPU analogue of flash-decoding's
     split-KV reduction.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     b, _, hq, d = q.shape
@@ -128,12 +127,12 @@ def gqa_decode_attention_cp(q: jax.Array, k_cache: jax.Array,
         out = out / jnp.maximum(l[..., None], 1e-30)
         return out.reshape(-1, 1, hq, d).astype(q_l.dtype)
 
-    f = shard_map(
+    f = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec), P(bspec, seq_axis), P(bspec, seq_axis),
                   P(bspec)),
         out_specs=P(bspec),
-        check_rep=False)
+        check_vma=False)
     return f(q, k_cache, v_cache, lengths)
 
 

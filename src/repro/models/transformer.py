@@ -523,14 +523,18 @@ def supports_paged(cfg: ModelConfig) -> Tuple[bool, str]:
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_tokens: int,
                      dtype=jnp.bfloat16) -> Dict[str, jax.Array]:
-    """One K and one V pool per layer: [L, num_blocks, block_tokens,
-    Hkv, D].  Block ids index axis 1; every request addresses the same
-    physical block id across all layers (one table, L pools)."""
+    """One K and one V pool per layer: [L, num_blocks, Hkv,
+    block_tokens, D].  Block ids index axis 1; every request addresses
+    the same physical block id across all layers (one table, L pools).
+    Pages are head-major so the paged kernels' per-head block
+    ``(1, 1, block_tokens, D)`` tiles the TPU's (8, 128) layout: a
+    token-major page would put a size-1 block on the second-minor
+    (head) axis, which the Pallas TPU lowering refuses."""
     ok, why = supports_paged(cfg)
     if not ok:
         raise NotImplementedError(why)
-    shape = (cfg.num_layers, num_blocks, block_tokens,
-             cfg.num_kv_heads, cfg.head_dim)
+    shape = (cfg.num_layers, num_blocks, cfg.num_kv_heads,
+             block_tokens, cfg.head_dim)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
@@ -543,7 +547,7 @@ def _attention_decode_paged(ap: dict, x, cfg: ModelConfig, k_pages, v_pages,
     their own entry point (see kernels.decode_attention.ops)."""
     from repro.kernels.decode_attention.ops import paged_decode_attention_impl \
         as paged_decode_attention
-    bt = k_pages.shape[1]
+    bt = k_pages.shape[2]
     q = jnp.einsum("bsd,dhk->bshk", x, ap["wq"])
     k = jnp.einsum("bsd,dhk->bshk", x, ap["wk"])
     v = jnp.einsum("bsd,dhk->bshk", x, ap["wv"])
@@ -554,8 +558,8 @@ def _attention_decode_paged(ap: dict, x, cfg: ModelConfig, k_pages, v_pages,
     phys = jnp.take_along_axis(block_tables, (positions // bt)[:, None],
                                axis=1)[:, 0]
     slot = positions % bt
-    k_pages = k_pages.at[phys, slot].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, slot].set(v[:, 0].astype(v_pages.dtype))
+    k_pages = k_pages.at[phys, :, slot].set(k[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[phys, :, slot].set(v[:, 0].astype(v_pages.dtype))
     out = paged_decode_attention(q[:, 0], k_pages, v_pages,
                                  block_tables, positions + 1)
     return (jnp.einsum("bshk,hkd->bsd", out[:, None].astype(x.dtype),
@@ -863,7 +867,7 @@ def write_prefill_pages_batched(pages, kv, tables, *, null_block: int = 0,
     perfectly live allocatable block (``null_block`` has no safe
     default; callers with pad entries must pass their engine's)."""
     import numpy as np
-    bt = pages["k"].shape[2]
+    bt = pages["k"].shape[3]
     b = kv[0].shape[1]
     max_nb = max([len(t) for t in tables] + [pad_to])
     if max_nb == 0:
@@ -880,8 +884,8 @@ def write_prefill_pages_batched(pages, kv, tables, *, null_block: int = 0,
         if c.shape[2] < cap:
             c = jnp.pad(c, ((0, 0), (0, 0), (0, cap - c.shape[2]),
                             (0, 0), (0, 0)))
-        c = c.reshape(l, bb * max_nb, bt, h, dh).astype(pool.dtype)
-        return pool.at[:, idx].set(c)
+        c = c.reshape(l, bb * max_nb, bt, h, dh).swapaxes(2, 3)
+        return pool.at[:, idx].set(c.astype(pool.dtype))
 
     k, v = kv
     return {"k": put(pages["k"], k), "v": put(pages["v"], v)}
@@ -905,7 +909,7 @@ def write_suffix_pages_batched(pages, kv, block_tables, starts, lengths,
 
     Shape-stable per ``(B, S, M)``: tables/starts/lengths are data, so a
     warmed engine never re-compiles this for a new hit mix."""
-    bt = pages["k"].shape[2]
+    bt = pages["k"].shape[3]
     nb_total = pages["k"].shape[1]
     k, v = kv
     l, b, s, h, dh = k.shape
@@ -920,8 +924,10 @@ def write_suffix_pages_batched(pages, kv, block_tables, starts, lengths,
     fs = slot.reshape(-1)
 
     def put(pool, c):
-        vals = c.reshape(l, b * s, h, dh).astype(pool.dtype)
-        return pool.at[:, fp, fs].set(vals, mode="drop")
+        # the block and slot indices are split by the head axis, so NumPy
+        # indexing rules put the token axis first: [B*S, L, Hkv, D]
+        vals = c.reshape(l, b * s, h, dh).swapaxes(0, 1).astype(pool.dtype)
+        return pool.at[:, fp, :, fs].set(vals, mode="drop")
 
     return {"k": put(pages["k"], k), "v": put(pages["v"], v)}
 
@@ -942,7 +948,7 @@ def copy_pages(pages, src, dst) -> Dict[str, jax.Array]:
 
 def gather_pages(pages, blocks) -> jax.Array:
     """Stack the pools' pages at ``blocks`` for a host swap-out
-    (DESIGN.md §15): one ``[P, L, N, bt, Hkv, D]`` array with the pool
+    (DESIGN.md §15): one ``[P, L, N, Hkv, bt, D]`` array with the pool
     axis in sorted key order ("k", "v"), so the single device→host
     readback of the result is the whole swap transfer.  ``blocks`` is
     int32 ``[N]``; callers pad to a warmed power-of-two N with the null
@@ -953,7 +959,7 @@ def gather_pages(pages, blocks) -> jax.Array:
 def scatter_pages(pages, blocks, values) -> Dict[str, jax.Array]:
     """Write swapped-in host pages back into the device pools — the
     inverse of :func:`gather_pages`, one scatter per pool.  ``values``
-    is ``[P, L, N, bt, Hkv, D]`` aligned with ``blocks``; pad entries
+    is ``[P, L, N, Hkv, bt, D]`` aligned with ``blocks``; pad entries
     target the null block, whose contents are junk by design."""
     return {key: pages[key].at[:, blocks].set(
                 values[i].astype(pages[key].dtype))

@@ -47,6 +47,7 @@ from repro.configs.base import ModelConfig
 from repro.core.types import Batch, Request
 from repro.core.wma import batch_wma
 from repro.models import model as M
+from repro.models.transformer import cast_params
 from repro.serving.faults import FaultInjector, Shed
 from repro.serving.paged_cache import (BlockAllocator, HostSwapTier,
                                        MispredictionEWMA, NULL_SEQ,
@@ -392,7 +393,7 @@ class ContinuousEngine:
 class PagedContinuousEngine:
     """Continuous batching over a shared physical block pool.
 
-    KV lives in per-layer pools ``[L, num_blocks, block_tokens, Hkv, D]``;
+    KV lives in per-layer pools ``[L, num_blocks, Hkv, block_tokens, D]``;
     each active request owns a block table (allocator seq_id = its slot).
     Admission reserves ``L(p) + G'(p)`` tokens of blocks — the *predicted*
     generation length, not G_max — so concurrency at a given Θ is bounded
@@ -496,8 +497,11 @@ class PagedContinuousEngine:
                               + (draft_k if spec_decode else 0)) // self.bt)
         # the null block: every pad/idle table entry points here
         self.null_block = self.allocator.allocate(self._NULL_SEQ, 1)[0]
-        self.params = params if params is not None else M.init_params(
-            cfg, jax.random.PRNGKey(seed))
+        # weights live in the serving dtype: the jitted steps' at-use
+        # cast is then a no-op instead of a full-weight convert per call
+        self.params = cast_params(params if params is not None
+                                  else M.init_params(
+                                      cfg, jax.random.PRNGKey(seed)), dtype)
         jt = _jitted(cfg, dtype)
         self._prefill_wave = jt["prefill_wave"]
         self._copy_pages = jt["copy_pages"]
@@ -615,10 +619,11 @@ class PagedContinuousEngine:
             # self-draft (no explicit draft cfg or params) shares the
             # target weights: the acceptance-rate ceiling and the bench
             # sanity config — every proposal must verify
-            self.draft_params = (
+            self.draft_params = cast_params(
                 draft_params if draft_params is not None
                 else self.params if draft_cfg is None
-                else M.init_params(dcfg, jax.random.PRNGKey(draft_seed)))
+                else M.init_params(dcfg, jax.random.PRNGKey(draft_seed)),
+                dtype)
             djt = _jitted(dcfg, dtype)
             self._draft_prefill_wave = djt["prefill_wave"]
             self._draft_window = djt["draft_window"]

@@ -564,7 +564,7 @@ class HostSwapTier:
 
     The device pool is tier 0; this is tier 1: a pinned numpy array of
     page slots shaped like the device pools' page axis, stacked over the
-    pools (``[P, L, slots, block_tokens, Hkv, D]``, P = len(pools) in
+    pools (``[P, L, slots, Hkv, block_tokens, D]``, P = len(pools) in
     sorted key order).  When the engine suspends a request it copies the
     request's pages here, frees its device blocks, and records a
     **per-sequence swap map** (host slot per table position) so the

@@ -8,6 +8,7 @@ this module provides a seeded, minimal re-implementation of the subset
 the suite uses (``integers``, ``floats``, ``booleans``, ``lists``,
 ``tuples``, ``sampled_from``) so the properties still execute on random
 inputs — without shrinking, the database, or deadline handling.
+Explicit ``@example`` cases run first, as with hypothesis.
 
 Usage (in test modules)::
 
@@ -116,6 +117,8 @@ def given(*arg_strategies: Strategy, **kw_strategies: Strategy):
         def run(*args, **kwargs):
             n = getattr(run, "_max_examples",
                         getattr(fn, "_max_examples", _DEFAULT_EXAMPLES))
+            for ex_args, ex_kwargs in getattr(fn, "_examples", ()):
+                fn(*args, *ex_args, **kwargs, **ex_kwargs)
             rng = random.Random(_SEED)
             for _ in range(n):
                 drawn = [s.draw(rng) for s in arg_strategies]
@@ -128,6 +131,15 @@ def given(*arg_strategies: Strategy, **kw_strategies: Strategy):
         run.__module__ = fn.__module__
         run.hypothesis_shim = True
         return run
+    return deco
+
+
+def example(*args, **kwargs):
+    """Record an explicit example, run before the drawn ones (apply it
+    below ``@given``, as with hypothesis)."""
+    def deco(fn):
+        fn._examples = [(args, kwargs)] + list(getattr(fn, "_examples", ()))
+        return fn
     return deco
 
 

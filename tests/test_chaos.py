@@ -312,7 +312,7 @@ def test_requeued_request_prefills_only_its_suffix():
 # property: random fault schedules never break the contract
 # ---------------------------------------------------------------------------
 
-@settings(max_examples=4)
+@settings(max_examples=4, deadline=None)
 @given(st.lists(st.tuples(st.integers(0, 5),
                           st.sampled_from(["pool_shrink", "stall",
                                            "poison_logits",

@@ -222,3 +222,21 @@ def test_predictor_continuous_learning_reduces_error():
     assert p.n_retrains > 0
     after = p.rmse(test)
     assert after <= before * 1.05
+
+
+# ------------------------------------------------- hypothesis fallback ----
+def test_fallback_shim_runs_explicit_examples_first():
+    """The bare-env shim (repro.testing) honours ``@example`` like
+    hypothesis: recorded cases run before the seeded draws."""
+    from repro import testing
+    seen = []
+
+    @testing.settings(max_examples=2, deadline=None)
+    @testing.given(testing.st.integers(0, 5))
+    @testing.example(n=99)
+    def prop(n):
+        seen.append(n)
+
+    prop()
+    assert seen[0] == 99 and len(seen) == 3
+    assert all(0 <= n <= 5 for n in seen[1:])

@@ -17,10 +17,10 @@ import numpy as np
 import pytest
 
 try:
-    from hypothesis import given, settings
+    from hypothesis import example, given, settings
     from hypothesis import strategies as st
 except ImportError:
-    from repro.testing import given, settings
+    from repro.testing import example, given, settings
     from repro.testing import strategies as st
 
 import jax
@@ -209,12 +209,13 @@ def _prop_engine():
     return _PROP_ENGINE["eng"]
 
 
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(st.integers(min_value=1, max_value=5),
        st.lists(st.tuples(st.integers(min_value=1, max_value=12),
                           st.integers(min_value=1, max_value=12)),
                 min_size=5, max_size=5),
        st.integers(min_value=0, max_value=10_000))
+@example(n=1, gens=[(1, 1)] * 5, seed=0)
 def test_fusion_windows_never_skip_events(n, gens, seed):
     """Drive random (target, prediction) workloads through the fused
     engine, checking after every window that (a) no request decoded past

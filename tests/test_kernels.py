@@ -142,8 +142,8 @@ def test_decode_attention_int8(s, hq, hkv, d):
 def _paged_setup(b, nb, bt, hq, hkv, d, mb, lengths, dtype=jnp.float32):
     """Random pool + disjoint per-request tables covering ``lengths``."""
     q = jax.random.normal(KEY, (b, hq, d), dtype)
-    kp = jax.random.normal(jax.random.fold_in(KEY, 1), (nb, bt, hkv, d), dtype)
-    vp = jax.random.normal(jax.random.fold_in(KEY, 2), (nb, bt, hkv, d), dtype)
+    kp = jax.random.normal(jax.random.fold_in(KEY, 1), (nb, hkv, bt, d), dtype)
+    vp = jax.random.normal(jax.random.fold_in(KEY, 2), (nb, hkv, bt, d), dtype)
     tables = jnp.zeros((b, mb), jnp.int32)
     nxt = 1                      # block 0 plays the shared null/pad block
     for i, ln in enumerate(lengths):
@@ -187,8 +187,8 @@ def test_paged_matches_dense_decode_attention():
     v = jax.random.normal(jax.random.fold_in(KEY, 2), (b, s, hkv, d))
     lengths = jnp.array([50, 29])
     # request i's pages are the contiguous slices of its own dense cache
-    kp = k.reshape(b * (s // bt), bt, hkv, d)
-    vp = v.reshape(b * (s // bt), bt, hkv, d)
+    kp = k.reshape(b * (s // bt), bt, hkv, d).swapaxes(1, 2)
+    vp = v.reshape(b * (s // bt), bt, hkv, d).swapaxes(1, 2)
     tables = jnp.arange(b * (s // bt), dtype=jnp.int32).reshape(b, s // bt)
     ref_dense = decode_attention_ref(q, k, v, lengths)
     ref_paged = paged_decode_attention_ref(q, kp, vp, tables, lengths)
@@ -210,8 +210,8 @@ def test_paged_decode_attention_masks_foreign_pages():
                                          interpret=True)
     # poison: block 0 (null), request 0's tail (23 % 16 = 7 into block 2),
     # and all of request 1's blocks as seen from request 0's table mask
-    kp2 = kp.at[0].set(1e4).at[2, 7:].set(-1e4)
-    vp2 = vp.at[0].set(1e4).at[2, 7:].set(-1e4)
+    kp2 = kp.at[0].set(1e4).at[2, :, 7:].set(-1e4)
+    vp2 = vp.at[0].set(1e4).at[2, :, 7:].set(-1e4)
     out2 = paged_decode_attention_kernel(q, kp2, vp2, tables, lens,
                                          interpret=True)
     assert jnp.allclose(out1[0], out2[0], atol=1e-5)

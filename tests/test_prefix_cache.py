@@ -218,8 +218,8 @@ def test_prefix_prefill_kernel_matches_oracle(bt, hq, hkv, d, s, plens,
     q = jax.random.normal(KEY, (b, s, hq, d), dtype)
     ks = jax.random.normal(jax.random.fold_in(KEY, 1), (b, s, hkv, d), dtype)
     vs = jax.random.normal(jax.random.fold_in(KEY, 2), (b, s, hkv, d), dtype)
-    kp = jax.random.normal(jax.random.fold_in(KEY, 3), (nb, bt, hkv, d), dtype)
-    vp = jax.random.normal(jax.random.fold_in(KEY, 4), (nb, bt, hkv, d), dtype)
+    kp = jax.random.normal(jax.random.fold_in(KEY, 3), (nb, hkv, bt, d), dtype)
+    vp = jax.random.normal(jax.random.fold_in(KEY, 4), (nb, hkv, bt, d), dtype)
     tables = np.zeros((b, mb), np.int32)
     nxt = 1
     for i, p in enumerate(plens):
@@ -252,16 +252,16 @@ def test_prefix_prefill_kernel_masks_foreign_pages():
     q = jax.random.normal(KEY, (b, s, hq, d))
     ks = jax.random.normal(jax.random.fold_in(KEY, 1), (b, s, hkv, d))
     vs = jax.random.normal(jax.random.fold_in(KEY, 2), (b, s, hkv, d))
-    kp = jax.random.normal(jax.random.fold_in(KEY, 3), (nb, bt, hkv, d))
-    vp = jax.random.normal(jax.random.fold_in(KEY, 4), (nb, bt, hkv, d))
+    kp = jax.random.normal(jax.random.fold_in(KEY, 3), (nb, hkv, bt, d))
+    vp = jax.random.normal(jax.random.fold_in(KEY, 4), (nb, hkv, bt, d))
     tables = jnp.asarray([[1, 2, 0], [3, 4, 5]], jnp.int32)
     args = (jnp.asarray(plens, jnp.int32), jnp.asarray(slens, jnp.int32))
     out1 = paged_prefix_prefill_attention_kernel(q, ks, vs, kp, vp, tables,
                                                  *args, interpret=True)
     # poison: null block 0, request 0's tail (12 % 8 = 4 into block 2),
     # and request 1's pages as seen from request 0
-    kp2 = kp.at[0].set(1e4).at[2, 4:].set(-1e4).at[3].set(1e4)
-    vp2 = vp.at[0].set(1e4).at[2, 4:].set(-1e4).at[3].set(1e4)
+    kp2 = kp.at[0].set(1e4).at[2, :, 4:].set(-1e4).at[3].set(1e4)
+    vp2 = vp.at[0].set(1e4).at[2, :, 4:].set(-1e4).at[3].set(1e4)
     out2 = paged_prefix_prefill_attention_kernel(q, ks, vs, kp2, vp2, tables,
                                                  *args, interpret=True)
     assert jnp.allclose(out1[0], out2[0], atol=1e-5)

@@ -258,12 +258,12 @@ def test_midblock_suffix_prefill_matches_full_prefill(params):
     assert err < 1e-4, err
     # the mid-block scatter writes slots 4.. of the clone and leaves the
     # copied prefix KV (slots 0-3) bit-identical
-    before = pages["k"][:, clone, :4]
+    before = pages["k"][:, clone, :, :4]
     pages2 = M.write_suffix_pages_batched(pages, kv, rows_j, plens, slens,
                                           null_block=0)
-    assert bool(jnp.all(pages2["k"][:, clone, :4] == before))
-    assert not bool(jnp.all(pages2["k"][:, clone, 4:5] ==
-                            pages["k"][:, clone, 4:5])), \
+    assert bool(jnp.all(pages2["k"][:, clone, :, :4] == before))
+    assert not bool(jnp.all(pages2["k"][:, clone, :, 4:5] ==
+                            pages["k"][:, clone, :, 4:5])), \
         "suffix KV must actually land in the clone's tail slots"
 
 

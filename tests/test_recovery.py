@@ -180,7 +180,7 @@ def test_crash_mid_swap(tmp_path):
 
 
 @given(seam=st.sampled_from(SEAMS), window=st.integers(0, 6))
-@settings(max_examples=6)
+@settings(max_examples=6, deadline=None)
 def test_crash_random_seam_property(seam, window):
     """Hypothesis sweep: ANY (seam, window) either never fires (the run
     completes normally, bit-exact) or recovers bit-exact with zero

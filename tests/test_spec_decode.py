@@ -206,7 +206,7 @@ def test_truncate_unit():
     assert alloc.used_blocks == 0
 
 
-@settings(max_examples=40)
+@settings(max_examples=40, deadline=None)
 @given(st.integers(min_value=4, max_value=12),
        st.integers(min_value=0, max_value=12),
        st.lists(st.integers(min_value=0, max_value=12),

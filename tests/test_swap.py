@@ -382,7 +382,7 @@ def _prop_reference():
     return _PROP_REF
 
 
-@settings(max_examples=5)
+@settings(max_examples=5, deadline=None)
 @given(st.lists(st.tuples(st.integers(0, 3),
                           st.sampled_from(["swap", "evict", "resume",
                                            "step"])),
