@@ -41,6 +41,12 @@ class ServingTimeEstimator:
         self.knn.fit(np.stack(self._x), np.array(self._y))
         return self
 
+    @property
+    def fitted(self) -> bool:
+        """Whether any serving time has been learned (else ``estimate``
+        raises and HRRN ranks batches by queueing time alone)."""
+        return self.knn.fitted
+
     def estimate(self, batch: Batch) -> float:
         """Uses the max *predicted* generation length as G(B)."""
         x = batch_features(batch.size, batch.length,

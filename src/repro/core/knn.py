@@ -27,6 +27,10 @@ class KNNRegressor:
         self._y = np.asarray(y, np.float32)
         return self
 
+    @property
+    def fitted(self) -> bool:
+        return self._x is not None
+
     def predict(self, x: np.ndarray) -> np.ndarray:
         if self._x is None:
             raise RuntimeError("fit() before predict()")

@@ -27,6 +27,7 @@ from repro.core.types import Batch, Request
 from repro.core.wma import MemoryModel
 from repro.serving.paged_cache import (BlockAllocator, PagedMemoryModel,
                                        RadixPrefixCache)
+from repro.serving.trace import span
 
 STRATEGIES = ("vs", "vsq", "ccb", "glp", "abp", "magnus",
               "ccb-paged", "magnus-paged")
@@ -114,8 +115,13 @@ class MagnusService:
     # -- ingress -------------------------------------------------------------
     def on_request(self, req: Request, now: float) -> Batch:
         if self.uses_prediction:
-            req.predicted_gen_length = self.predictor.predict(req)
-            return self.batcher.insert(req, now)
+            with span("magnus.predict", req_id=req.req_id,
+                      input_tokens=req.length):
+                req.predicted_gen_length = self.predictor.predict(req)
+            with span("magnus.batch", req_id=req.req_id) as s:
+                batch = self.batcher.insert(req, now)
+                s.set_metadata(queued=len(self.batcher.queue))
+            return batch
         # vanilla: FCFS fill of the newest batch up to the fixed beta
         req.predicted_gen_length = self.memory.max_gen
         q = self.batcher.queue
@@ -128,9 +134,12 @@ class MagnusService:
 
     # -- dispatch ------------------------------------------------------------
     def next_batch(self, now: float) -> Optional[Batch]:
-        b = self.scheduler.select(self.batcher.queue, now)
-        if b is not None:
-            self.batcher.pop(b)
+        with span("magnus.schedule", queued=len(self.batcher.queue),
+                  estimator_fit=self.estimator.fitted) as s:
+            b = self.scheduler.select(self.batcher.queue, now)
+            if b is not None:
+                self.batcher.pop(b)
+                s.set_metadata(size=b.size)
         return b
 
     def estimate_time(self, batch: Batch) -> float:

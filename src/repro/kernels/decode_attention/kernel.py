@@ -289,6 +289,7 @@ def paged_prefix_prefill_attention_kernel(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, s * g, d), q.dtype),
         interpret=interpret,
+        name="paged_prefix_prefill_attention",
     )(block_tables.astype(jnp.int32), prefix_lens.astype(jnp.int32),
       suffix_lens.astype(jnp.int32), qt, kt, vt, k_pages, v_pages)
     return out.reshape(b, hkv, s, g, d).transpose(0, 2, 1, 3, 4) \
@@ -337,6 +338,7 @@ def paged_decode_attention_kernel(q: jax.Array, k_pages: jax.Array,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), lengths.astype(jnp.int32),
       qt, k_pages, v_pages)
     return out.reshape(b, hq, d)
@@ -394,6 +396,7 @@ def decode_attention_int8_kernel(q: jax.Array, k_cache: jax.Array,
             pltpu.VMEM((g, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention_int8",
     )(lengths.astype(jnp.int32), qt, kt, vt,
       kst.astype(jnp.float32), vst.astype(jnp.float32))
     return out.reshape(b, hkv, g, d).reshape(b, hq, d)
@@ -440,5 +443,6 @@ def decode_attention_kernel(q: jax.Array, k_cache: jax.Array,
             pltpu.VMEM((g, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="decode_attention",
     )(lengths.astype(jnp.int32), qt, kt, vt)
     return out.reshape(b, hkv, g, d).reshape(b, hq, d)

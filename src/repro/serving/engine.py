@@ -35,7 +35,7 @@ import functools
 import math
 import time
 from collections import deque
-from typing import Deque, Dict, Iterable, List, Optional, Set, Tuple
+from typing import Collection, Deque, Dict, List, Optional, Set, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -52,6 +52,7 @@ from repro.serving.faults import FaultInjector, Shed
 from repro.serving.paged_cache import (BlockAllocator, HostSwapTier,
                                        MispredictionEWMA, NULL_SEQ,
                                        PrefixMatch, RadixPrefixCache)
+from repro.serving.trace import span
 from repro.workload.tokenizer import encode
 
 
@@ -125,6 +126,15 @@ def _restore_slot(tables, positions, active, logits, slot, row, pos,
             logits.at[slot].set(logits_row))
 
 
+def _named_partial(fn, **bound):
+    """``functools.partial`` that keeps ``fn``'s name: the jitted program
+    is then ``jit_<fn>`` in the profiler and the compiled HLO (a bare
+    partial compiles as ``jit__unknown``)."""
+    p = functools.partial(fn, **bound)
+    p.__name__ = fn.__name__
+    return p
+
+
 @functools.lru_cache(maxsize=None)
 def _jitted(cfg: ModelConfig, dtype):
     """One jitted entry-point set per (config, dtype), shared by every
@@ -139,27 +149,27 @@ def _jitted(cfg: ModelConfig, dtype):
     and never reads anything back."""
     return {
         "prefill": jax.jit(
-            functools.partial(M.prefill, cfg=cfg, act_dtype=dtype),
+            _named_partial(M.prefill, cfg=cfg, act_dtype=dtype),
             static_argnames=("cache_len",)),
         # every decode entry point donates its KV buffer: each step writes
         # one token's KV back into the same cache/pool, so without donation
         # XLA keeps two full copies live across the dispatch (and hotlint
         # HL003 flags the rebind-without-donate call sites)
         "decode": jax.jit(
-            functools.partial(M.decode_step, cfg=cfg, act_dtype=dtype),
+            _named_partial(M.decode_step, cfg=cfg, act_dtype=dtype),
             donate_argnames=("cache",)),
         "decode_multi": jax.jit(
-            functools.partial(M.decode_multi, cfg=cfg, act_dtype=dtype),
+            _named_partial(M.decode_multi, cfg=cfg, act_dtype=dtype),
             static_argnames=("num_steps",), donate_argnames=("cache",)),
         "decode_paged": jax.jit(
-            functools.partial(M.decode_step_paged, cfg=cfg, act_dtype=dtype),
+            _named_partial(M.decode_step_paged, cfg=cfg, act_dtype=dtype),
             donate_argnames=("pages",)),
         "decode_multi_paged": jax.jit(
-            functools.partial(M.decode_multi_paged, cfg=cfg,
-                              act_dtype=dtype),
+            _named_partial(M.decode_multi_paged, cfg=cfg,
+                           act_dtype=dtype),
             static_argnames=("num_steps",), donate_argnames=("pages",)),
         "prefill_wave": jax.jit(
-            functools.partial(M.prefill_wave, cfg=cfg, act_dtype=dtype),
+            _named_partial(M.prefill_wave, cfg=cfg, act_dtype=dtype),
             donate_argnames=("pages", "state")),
         # grow-path COW clones (decode side): donated so the in-place
         # page copy never duplicates the pool — §12's full-span
@@ -184,11 +194,11 @@ def _jitted(cfg: ModelConfig, dtype):
         # donate their own pool only — positions/logits are carried
         # state the engine rebinds, matching decode_multi_paged
         "draft_window": jax.jit(
-            functools.partial(M.draft_window, cfg=cfg, act_dtype=dtype),
+            _named_partial(M.draft_window, cfg=cfg, act_dtype=dtype),
             static_argnames=("num_steps", "target_vocab"),
             donate_argnames=("pages",)),
         "verify_window": jax.jit(
-            functools.partial(M.verify_window, cfg=cfg, act_dtype=dtype),
+            _named_partial(M.verify_window, cfg=cfg, act_dtype=dtype),
             donate_argnames=("pages",)),
     }
 
@@ -754,8 +764,9 @@ class PagedContinuousEngine:
             # restore re-derives nothing from them)
             self.faults.crash_due("publish", self.windows)
         queue, self._publish_queue = self._publish_queue, []
-        for ids, table in queue:
-            self.prefix_cache.insert(ids, table)
+        with span("radix.publish", spans=len(queue)):
+            for ids, table in queue:
+                self.prefix_cache.insert(ids, table)
 
     def reserve_tokens(self, req: Request,
                        n_prompt: Optional[int] = None) -> int:
@@ -1124,7 +1135,16 @@ class PagedContinuousEngine:
                     _bucket(max(len(a["ids"]) - a["cached"], 1)),
                     []).append(a)
             for sb in sorted(buckets):
-                self._dispatch_wave(buckets[sb])
+                plans = buckets[sb]
+                with span("engine.prefill_wave") as s:
+                    if s:
+                        cached = sum(int(p["cached"]) for p in plans)
+                        s.set_metadata(
+                            rows=len(plans), bucket=sb, cached_tokens=cached,
+                            suffix_tokens=sum(len(p["ids"]) for p in plans)
+                            - cached,
+                            req_ids=[p["req"].req_id for p in plans])
+                    self._dispatch_wave(plans)
 
     @hot_path
     def join(self, req: Request) -> int:
@@ -1136,7 +1156,7 @@ class PagedContinuousEngine:
         return int(plan["slot"])
 
     @hot_path
-    def join_many(self, reqs: Iterable[Request]) -> int:
+    def join_many(self, reqs: Collection[Request]) -> int:
         """Admit the longest admissible prefix of ``reqs`` as ONE
         admission wave: radix-aware ordering (same-wave chain sharers
         admit a generation after their chain's publisher), then one
@@ -1146,21 +1166,23 @@ class PagedContinuousEngine:
         caller pops that many).  Stops at the first request that does
         not fit (FIFO admission, same discipline as repeated ``join``).
         """
-        self._flush_publishes()
-        self._resume_swapped()   # suspended requests outrank admissions
-        self._wave_pending = []
-        admitted = []
-        for req in reqs:
-            try:
-                admitted.append(self._reserve(req))
-            except EngineFull:
-                break
-        if admitted:
-            if self.faults is not None:
-                # §17 crash seam: mid-wave — reservations made, prefill
-                # not yet dispatched (the WAL already holds the admits)
-                self.faults.crash_due("wave", self.windows)
-            self._prefill_admitted(admitted)
+        with span("engine.admit", offered=len(reqs)) as s:
+            self._flush_publishes()
+            self._resume_swapped()   # suspended requests outrank admissions
+            self._wave_pending = []
+            admitted = []
+            for req in reqs:
+                try:
+                    admitted.append(self._reserve(req))
+                except EngineFull:
+                    break
+            if admitted:
+                if self.faults is not None:
+                    # §17 crash seam: mid-wave — reservations made, prefill
+                    # not yet dispatched (the WAL already holds the admits)
+                    self.faults.crash_due("wave", self.windows)
+                self._prefill_admitted(admitted)
+            s.set_metadata(admitted=len(admitted))
         return len(admitted)
 
     # -- eviction ------------------------------------------------------------
@@ -1622,6 +1644,67 @@ class PagedContinuousEngine:
             self.allocator.free_seq(slot)
             self._release(slot)
 
+    def _grow_window(self, evicted: List[Request]) -> None:
+        """The grow loop before a window: every active slot grows to hold
+        the window's writes (:meth:`_grow`), its copy-on-write page copies
+        applied at once; a failed grow raises :class:`PoolExhausted`."""
+        with span("engine.grow") as g:
+            cow0, grown = self.cow_copies, 0
+            try:
+                for slot, a in enumerate(self.active):
+                    if a is None:
+                        continue
+                    had = len(self.allocator.tables[slot])
+                    try:
+                        pairs = self._grow(slot, evicted)
+                    except MemoryError:
+                        if self.faults is not None and self.faults.held_blocks:
+                            # transient fault-held pool: evict the growing
+                            # request itself (requeued by the caller) instead
+                            # of failing the window — a pool_restore later in
+                            # the plan lets it finish
+                            evicted.append(self._evict(slot))
+                            continue
+                        raise
+                    grown += len(self.allocator.tables[slot]) > had
+                    # apply this slot's COW page copies IMMEDIATELY: a
+                    # later slot's _grow may evict this one and recycle
+                    # its clone block — deferring to one batched copy
+                    # would scatter stale pages into the new owner
+                    # (duplicate destinations, undefined winner), and a
+                    # later MemoryError would leave the clone's table
+                    # swap applied but its prefix KV never copied
+                    if pairs:
+                        npairs = _pow2_ceil(len(pairs))
+                        src = np.full(npairs, self.null_block, np.int32)
+                        dst = np.full(npairs, self.null_block, np.int32)
+                        for i, (s, d) in enumerate(pairs):
+                            src[i], dst[i] = s, d
+                        self.pages = self._copy_pages(self.pages, src, dst)
+                    if self.spec_decode and not a.get("draft_cold"):
+                        # the slot's draft pool grows to the same pos+spec_w
+                        # target through the same valves (after the COW
+                        # copies above so an eviction here cannot recycle a
+                        # clone source before its page copy ran)
+                        try:
+                            self._grow_draft(slot, evicted)
+                        except MemoryError:
+                            if self.faults is not None \
+                                    and self.faults.held_blocks:
+                                evicted.append(self._evict(slot))
+                                continue
+                            raise
+            except MemoryError as e:
+                # don't strand anything on a failed grow: requests evicted
+                # earlier in this same step ride the typed exception for
+                # requeue, and the culprit slot is freed (and attached) so
+                # the engine stays serviceable and drainable after the raise
+                culprit = (self._evict(slot)
+                           if self.active[slot] is not None else None)
+                raise PoolExhausted(str(e), evicted=tuple(evicted),
+                                    culprit=culprit) from e
+            g.set_metadata(grown=grown, cow=self.cow_copies - cow0)
+
     def step_window(self, max_steps: Optional[int] = None
                     ) -> Tuple[List[Request], List[Request], int]:
         """Run one fused decode window over all active requests.
@@ -1636,179 +1719,146 @@ class PagedContinuousEngine:
         one a fault-free engine would run.  A stalled window burns
         scheduler-clock ticks and returns ``steps_run == 0`` without
         dispatching."""
-        self.windows += 1
-        stalled = 0
-        evicted: List[Request] = []
-        if self.faults is not None:
-            # the fault seam fires even with nothing active: a restore
-            # event must be able to un-wedge an engine whose whole active
-            # set was evicted by the matching shrink
+        with span("engine.window") as win:
+            self.windows += 1
+            stalled = 0
+            evicted: List[Request] = []
+            if self.faults is not None:
+                # the fault seam fires even with nothing active: a restore
+                # event must be able to un-wedge an engine whose whole active
+                # set was evicted by the matching shrink
+                self._flush_publishes()
+                stalled = self.faults.before_window(self)
+                if stalled:
+                    self.clock += stalled
+                    self.stall_ticks += stalled
+            if self.swap is not None and self._swapped:
+                # suspended images first (§15): expire the hopeless, resume
+                # whatever fits — BEFORE the idle check, or an engine whose
+                # whole active set is suspended could never wake up
+                self._expire_swapped()
+                self._resume_swapped()
+            if not any(a is not None for a in self.active):
+                return [], [], 0
+            # deferred radix publishes land here — between admission waves,
+            # off the admission hot path, and before any grow/evict/finish
+            # could free a queued span's blocks
             self._flush_publishes()
-            stalled = self.faults.before_window(self)
-            if stalled:
-                self.clock += stalled
-                self.stall_ticks += stalled
-        if self.swap is not None and self._swapped:
-            # suspended images first (§15): expire the hopeless, resume
-            # whatever fits — BEFORE the idle check, or an engine whose
-            # whole active set is suspended could never wake up
-            self._expire_swapped()
-            self._resume_swapped()
-        if not any(a is not None for a in self.active):
-            return [], [], 0
-        # deferred radix publishes land here — between admission waves,
-        # off the admission hot path, and before any grow/evict/finish
-        # could free a queued span's blocks
-        self._flush_publishes()
-        self._expire_deadlines()
-        if self._nan_guard and any(a is not None for a in self.active):
-            # hotlint: sync(§14 NaN/Inf quarantine guard readback)
-            finite = np.isfinite(np.asarray(self.logits)).all(axis=1)
-            self.host_syncs += count_sync()
-            for slot, a in enumerate(self.active):
-                if a is not None and not bool(finite[slot]):
-                    # quarantine: clear the poisoned row (idle rows feed
-                    # the fused argmax, masked) and evict for readmission
-                    # — the restart re-prefills from the prompt, so the
-                    # re-served stream stays bit-exact
-                    self.logits = self.logits.at[slot].set(0.0)
-                    evicted.append(self._evict(slot))
-                    self.quarantined += 1
-        if (self.spec_decode and self._nan_guard
-                and any(a is not None for a in self.active)):
-            # §16 draft-health guard: a poisoned DRAFT must not kill the
-            # request — verification is the correctness oracle — so the
-            # guard ices the slot's draft permanently (proposals stop,
-            # the stream continues at one verified token per window)
-            # instead of evicting anything
-            # hotlint: sync(§16 draft-health guard readback)
-            dfinite = np.isfinite(np.asarray(self.draft_logits)).all(axis=1)
-            self.host_syncs += count_sync()
-            for slot, a in enumerate(self.active):
-                if a is not None and not a.get("draft_cold") \
-                        and not bool(dfinite[slot]):
-                    self._quarantine_draft(slot)
-        if stalled or not any(a is not None for a in self.active):
-            self.window_stats = None
-            return [], evicted, 0
-        if self.faults is not None:
-            # §17 crash seam: mid-window — prologue done (stalls burned,
-            # deadlines swept, guards run), decode not yet dispatched
-            self.faults.crash_due("window", self.windows)
-        try:
+            self._expire_deadlines()
+            if self._nan_guard and any(a is not None for a in self.active):
+                # hotlint: sync(§14 NaN/Inf quarantine guard readback)
+                finite = np.isfinite(np.asarray(self.logits)).all(axis=1)
+                self.host_syncs += count_sync()
+                for slot, a in enumerate(self.active):
+                    if a is not None and not bool(finite[slot]):
+                        # quarantine: clear the poisoned row (idle rows feed
+                        # the fused argmax, masked) and evict for readmission
+                        # — the restart re-prefills from the prompt, so the
+                        # re-served stream stays bit-exact
+                        self.logits = self.logits.at[slot].set(0.0)
+                        evicted.append(self._evict(slot))
+                        self.quarantined += 1
+            if (self.spec_decode and self._nan_guard
+                    and any(a is not None for a in self.active)):
+                # §16 draft-health guard: a poisoned DRAFT must not kill the
+                # request — verification is the correctness oracle — so the
+                # guard ices the slot's draft permanently (proposals stop,
+                # the stream continues at one verified token per window)
+                # instead of evicting anything
+                # hotlint: sync(§16 draft-health guard readback)
+                dfinite = np.isfinite(
+                    np.asarray(self.draft_logits)).all(axis=1)
+                self.host_syncs += count_sync()
+                for slot, a in enumerate(self.active):
+                    if a is not None and not a.get("draft_cold") \
+                            and not bool(dfinite[slot]):
+                        self._quarantine_draft(slot)
+            if stalled or not any(a is not None for a in self.active):
+                self.window_stats = None
+                return [], evicted, 0
+            if self.faults is not None:
+                # §17 crash seam: mid-window — prologue done (stalls burned,
+                # deadlines swept, guards run), decode not yet dispatched
+                self.faults.crash_due("window", self.windows)
+            self._grow_window(evicted)
+            if not any(a is not None for a in self.active):
+                self.window_stats = None
+                return [], evicted, 0
+            shadow = getattr(self.allocator, "_shadow", None)
+            if shadow is not None:
+                # the window appends from each slot's write cursor: every
+                # block at or past it must be privately owned (post-_grow COW)
+                for slot, a in enumerate(self.active):
+                    if a is not None:
+                        t = self.allocator.tables[slot]
+                        shadow.check_write(
+                            slot, t[int(self.pos_host[slot]) // self.bt:])
+                        if self.spec_decode and not a.get("draft_cold"):
+                            dseq = self._draft_seq(slot)
+                            dt = self.allocator.tables.get(dseq, [])
+                            shadow.check_write(
+                                dseq, dt[int(self.pos_host[slot]) // self.bt:])
+            if self.spec_decode:
+                finished, k = self._spec_window(max_steps)
+                return finished, evicted, k
+            k = self._window_steps()
+            if max_steps is not None:
+                k = max(1, min(k, max_steps))
+            # power-of-two windows bound the jit cache at O(log G_max) entries
+            k = _pow2_floor(k) if self.fuse else 1
+            # post-grow/evict snapshot: lets drivers reconstruct the exact
+            # per-iteration utilization ramp the per-token loop would sample
+            # (live tokens += num_active per iteration; blocks fixed in-window)
+            self.window_stats = {
+                "live0": int(sum(int(self.pos_host[s])
+                                 for s, a in enumerate(self.active)
+                                 if a is not None)),
+                "active": self.num_active,
+                "used_tokens": self.allocator.used_blocks * self.bt,
+            }
+            win.set_metadata(k=k, rows=self.window_stats["active"])
+            with span("engine.decode", k=k):
+                self.logits, self.pages, self.positions, toks = \
+                    self._decode_multi(
+                        self.params, pages=self.pages,
+                        batch={"logits": self.logits,
+                               "positions": self.positions,
+                               "block_tables": self.tables,
+                               "active": self.active_mask},
+                        num_steps=k)
+            with span("engine.readback"):
+                # hotlint: sync(the one window token readback — §9 fused)
+                toks = np.asarray(toks)
+                self.host_syncs += count_sync()
+            self.decode_steps += k
+            self.clock += k
+            finished = self._retire(toks, k)
+            return finished, evicted, k
+
+    def _retire(self, toks: np.ndarray, k: int) -> List[Request]:
+        """Append a window's ``k`` tokens per slot and retire the slots
+        that reached their target: stream kept, misprediction EWMA fed,
+        prefix unpinned, blocks freed, slot reset.  Returns the finished
+        requests."""
+        with span("engine.retire") as r:
+            finished = []
             for slot, a in enumerate(self.active):
                 if a is None:
                     continue
-                try:
-                    pairs = self._grow(slot, evicted)
-                except MemoryError:
-                    if self.faults is not None and self.faults.held_blocks:
-                        # transient fault-held pool: evict the growing
-                        # request itself (requeued by the caller) instead
-                        # of failing the window — a pool_restore later in
-                        # the plan lets it finish
-                        evicted.append(self._evict(slot))
-                        continue
-                    raise
-                # apply this slot's COW page copies IMMEDIATELY: a
-                # later slot's _grow may evict this one and recycle
-                # its clone block — deferring to one batched copy
-                # would scatter stale pages into the new owner
-                # (duplicate destinations, undefined winner), and a
-                # later MemoryError would leave the clone's table
-                # swap applied but its prefix KV never copied
-                if pairs:
-                    npairs = _pow2_ceil(len(pairs))
-                    src = np.full(npairs, self.null_block, np.int32)
-                    dst = np.full(npairs, self.null_block, np.int32)
-                    for i, (s, d) in enumerate(pairs):
-                        src[i], dst[i] = s, d
-                    self.pages = self._copy_pages(self.pages, src, dst)
-                if self.spec_decode and not a.get("draft_cold"):
-                    # the slot's draft pool grows to the same pos+spec_w
-                    # target through the same valves (after the COW
-                    # copies above so an eviction here cannot recycle a
-                    # clone source before its page copy ran)
-                    try:
-                        self._grow_draft(slot, evicted)
-                    except MemoryError:
-                        if self.faults is not None \
-                                and self.faults.held_blocks:
-                            evicted.append(self._evict(slot))
-                            continue
-                        raise
-        except MemoryError as e:
-            # don't strand anything on a failed grow: requests evicted
-            # earlier in this same step ride the typed exception for
-            # requeue, and the culprit slot is freed (and attached) so
-            # the engine stays serviceable and drainable after the raise
-            culprit = (self._evict(slot)
-                       if self.active[slot] is not None else None)
-            raise PoolExhausted(str(e), evicted=tuple(evicted),
-                                culprit=culprit) from e
-        if not any(a is not None for a in self.active):
-            self.window_stats = None
-            return [], evicted, 0
-        shadow = getattr(self.allocator, "_shadow", None)
-        if shadow is not None:
-            # the window appends from each slot's write cursor: every
-            # block at or past it must be privately owned (post-_grow COW)
-            for slot, a in enumerate(self.active):
-                if a is not None:
-                    t = self.allocator.tables[slot]
-                    shadow.check_write(
-                        slot, t[int(self.pos_host[slot]) // self.bt:])
-                    if self.spec_decode and not a.get("draft_cold"):
-                        dseq = self._draft_seq(slot)
-                        dt = self.allocator.tables.get(dseq, [])
-                        shadow.check_write(
-                            dseq, dt[int(self.pos_host[slot]) // self.bt:])
-        if self.spec_decode:
-            finished, k = self._spec_window(max_steps)
-            return finished, evicted, k
-        k = self._window_steps()
-        if max_steps is not None:
-            k = max(1, min(k, max_steps))
-        # power-of-two windows bound the jit cache at O(log G_max) entries
-        k = _pow2_floor(k) if self.fuse else 1
-        # post-grow/evict snapshot: lets drivers reconstruct the exact
-        # per-iteration utilization ramp the per-token loop would sample
-        # (live tokens += num_active per iteration; blocks fixed in-window)
-        self.window_stats = {
-            "live0": int(sum(int(self.pos_host[s])
-                             for s, a in enumerate(self.active)
-                             if a is not None)),
-            "active": self.num_active,
-            "used_tokens": self.allocator.used_blocks * self.bt,
-        }
-        self.logits, self.pages, self.positions, toks = self._decode_multi(
-            self.params, pages=self.pages,
-            batch={"logits": self.logits, "positions": self.positions,
-                   "block_tables": self.tables,
-                   "active": self.active_mask},
-            num_steps=k)
-        # hotlint: sync(the one window token readback — §9 fused decode)
-        toks = np.asarray(toks)
-        self.host_syncs += count_sync()
-        self.decode_steps += k
-        self.clock += k
-        finished = []
-        for slot, a in enumerate(self.active):
-            if a is None:
-                continue
-            a["generated"].extend(toks[slot, :k].tolist())
-            self.pos_host[slot] += k
-            if len(a["generated"]) >= a["target"]:
-                finished.append(a["req"])
-                self.generated[a["req"].req_id] = a["generated"]
-                # close the misprediction feedback loop (§14): observed
-                # generation length vs the reservation's predicted g
-                self.mispredict.observe(a["req"].app, a["reserve_g"],
-                                        len(a["generated"]))
-                self._unpin_prefix(slot)
-                self.allocator.free_seq(slot)
-                self._release(slot)
-        return finished, evicted, k
+                a["generated"].extend(toks[slot, :k].tolist())
+                self.pos_host[slot] += k
+                if len(a["generated"]) >= a["target"]:
+                    finished.append(a["req"])
+                    self.generated[a["req"].req_id] = a["generated"]
+                    # close the misprediction feedback loop (§14): observed
+                    # generation length vs the reservation's predicted g
+                    self.mispredict.observe(a["req"].app, a["reserve_g"],
+                                            len(a["generated"]))
+                    self._unpin_prefix(slot)
+                    self.allocator.free_seq(slot)
+                    self._release(slot)
+            r.set_metadata(finished=len(finished))
+        return finished
 
     def _quarantine_draft(self, slot: int) -> None:
         """Permanently ice a slot's draft (§16): free its draft pool,
