@@ -54,8 +54,18 @@ def paged_decode_attention(q, k_pages, v_pages, block_tables, lengths, *,
                            use_ref: bool = False):
     """Block-table paged decode attention (shared page pool; per-request
     tables).  ``use_ref`` or a program lowered for any platform but the
-    TPU takes the gather-based oracle — the Pallas path only pays off
-    when the pool lives in HBM and the tables keep the DMA set small."""
+    TPU takes the gather-based oracle.
+
+    The TPU kernel walks each row's live pages only, ``ceil(length /
+    block_tokens)`` of them: a scalar-core schedule lists every row's
+    chunks of ``P`` pages, and the kernel takes one grid step a chunk,
+    each page one DMA of its ``[Hkv, block_tokens, D]`` slab (all KV
+    heads), double-buffered so the next chunk's pages are in flight while
+    this one computes.  ``P`` is :func:`~repro.kernels.decode_attention.
+    kernel.decode_pages_per_step` of the page's bytes and the table width:
+    the largest power of two whose K pages fit a fixed VMEM share, at most
+    ``max_blocks``.  Pages past a row's length are never copied, so the
+    cost follows the live pages, not ``B x max_blocks``."""
     return paged_decode_attention_impl(q, k_pages, v_pages, block_tables,
                                        lengths, use_ref=use_ref)
 

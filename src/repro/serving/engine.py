@@ -46,6 +46,7 @@ from repro.analysis.sanitizer import count_sync, hot_path
 from repro.configs.base import ModelConfig
 from repro.core.types import Batch, Request
 from repro.core.wma import batch_wma
+from repro.kernels.decode_attention.kernel import decode_pages_per_step
 from repro.models import model as M
 from repro.models.transformer import cast_params
 from repro.serving.faults import FaultInjector, Shed
@@ -522,6 +523,9 @@ class PagedContinuousEngine:
         self.pages = M.init_paged_cache(
             cfg, self.allocator.num_blocks, self.bt,
             dtype=jnp.float32 if dtype == jnp.float32 else jnp.bfloat16)
+        kp = self.pages["k"]
+        self._pages_per_step = decode_pages_per_step(
+            math.prod(kp.shape[2:]) * kp.dtype.itemsize, self.max_blocks)
         b = self.slots
         self.active: List[Optional[dict]] = [None] * b
         self._null_row = jnp.full((self.max_blocks,), self.null_block,
@@ -1817,7 +1821,16 @@ class PagedContinuousEngine:
                 "used_tokens": self.allocator.used_blocks * self.bt,
             }
             win.set_metadata(k=k, rows=self.window_stats["active"])
-            with span("engine.decode", k=k):
+            with span("engine.decode", k=k) as dec:
+                if dec:
+                    # the paged decode kernel's work: its pages a step,
+                    # and the pages it walks at the window's first step
+                    dec.set_metadata(
+                        pages_per_step=self._pages_per_step,
+                        live_pages=sum(-(-(int(self.pos_host[s]) + 1)
+                                         // self.bt)
+                                       for s, a in enumerate(self.active)
+                                       if a is not None))
                 self.logits, self.pages, self.positions, toks = \
                     self._decode_multi(
                         self.params, pages=self.pages,

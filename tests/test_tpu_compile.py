@@ -24,6 +24,12 @@ WIDTHS = [("smollm-135m", 9, 3, 64), ("chatglm-6b", 32, 32, 128)]
 SLOTS, BLOCK_TOKENS, NUM_BLOCKS = 16, 16, 512
 MAX_BLOCKS = 16                 # (max_len 200 + max_gen 32) / 16, rounded up
 SUFFIX = 256                    # the engine's largest suffix bucket at max_len 200
+# (id, Hq, Hkv, D, max_blocks, num_blocks) of the paged decode kernel: the
+# widths above, and smollm-135m at the benchmark cells' shapes ((max_len
+# 512 + max_gen 1024) / 16 = 96-block tables, a 1600-block pool)
+DECODE_CASES = [(n, hq, hkv, d, MAX_BLOCKS, NUM_BLOCKS)
+                for n, hq, hkv, d in WIDTHS] \
+    + [("smollm-135m-bench", 9, 3, 64, 96, 1600)]
 
 
 @pytest.fixture(scope="module")
@@ -51,14 +57,16 @@ def _spec(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-@pytest.mark.parametrize("name,hq,hkv,d", WIDTHS, ids=[w[0] for w in WIDTHS])
-def test_paged_decode_kernel_compiles_for_v5e(one_chip, name, hq, hkv, d):
+@pytest.mark.parametrize("name,hq,hkv,d,max_blocks,num_blocks", DECODE_CASES,
+                         ids=[c[0] for c in DECODE_CASES])
+def test_paged_decode_kernel_compiles_for_v5e(one_chip, name, hq, hkv, d,
+                                              max_blocks, num_blocks):
     from repro.kernels.decode_attention.kernel import (
         paged_decode_attention_kernel)
     bf = jnp.bfloat16
-    pages = _spec((NUM_BLOCKS, hkv, BLOCK_TOKENS, d), bf, one_chip)
+    pages = _spec((num_blocks, hkv, BLOCK_TOKENS, d), bf, one_chip)
     args = (_spec((SLOTS, hq, d), bf, one_chip), pages, pages,
-            _spec((SLOTS, MAX_BLOCKS), jnp.int32, one_chip),
+            _spec((SLOTS, max_blocks), jnp.int32, one_chip),
             _spec((SLOTS,), jnp.int32, one_chip))
     compiled = jax.jit(paged_decode_attention_kernel).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text(), name

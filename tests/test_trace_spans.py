@@ -133,6 +133,26 @@ def test_window_attrs_count_the_decode(served):
     assert sum(decodes) == served["traced"].decode_steps
 
 
+def test_decode_attrs_count_the_kernel_pages(served):
+    """Each decode dispatch names the paged kernel's pages a step, as the
+    kernel picks them for this pool, and the live pages of its rows: at
+    least one a row, at most a full table a row."""
+    from repro.kernels.decode_attention.kernel import decode_pages_per_step
+    eng = served["traced"]
+    kp = eng.pages["k"]
+    want = decode_pages_per_step(
+        kp.shape[2] * kp.shape[3] * kp.shape[4] * kp.dtype.itemsize,
+        eng.max_blocks)
+    windows = [s for s in _named(served["spans"], "engine.window")
+               if "k" in s[4]]
+    decodes = _named(served["spans"], "engine.decode")
+    assert len(decodes) == len(windows)
+    for win, dec in zip(windows, decodes):
+        attrs, rows = dec[4], win[4]["rows"]
+        assert attrs["pages_per_step"] == want
+        assert rows <= attrs["live_pages"] <= rows * eng.max_blocks
+
+
 def test_schedule_spans_name_each_batch(served):
     """Each scheduling pass names the queue it chose from and whether the
     serving-time estimator behind HRRN was fit; the batches chosen hold
