@@ -1,4 +1,7 @@
-"""Plain reference of a llama-style dense decoder, in float32.
+"""The llama-style dense decoder: its plain reference in float32, and the
+architecture facts the harness takes from here (``arch.py``): the
+program's configuration, the parameter layout, the operation and byte
+counts and the prompt's ids.
 
 The architecture as published for the SmolLM / Llama family: token
 embedding, then per layer ``h += Attn(RMSNorm(h))`` and
@@ -9,10 +12,11 @@ output head.  Attention is causal grouped-query attention: query head
 rotate the first half of each head against the second (frequencies
 ``theta ** (-2j / head_dim)``).
 
-Nothing of the program is imported: the weights are made again from the
-seed by ``weights.py``, one layer at a time, so a model that fills the
-chip in bf16 fits here in float32.  Every matrix product runs at the
-highest precision.
+The reference takes nothing of the program (only ``program_config``,
+which the harness calls, builds the program's own configuration object):
+the weights are made again from the seed by ``weights.py``, one layer at
+a time, so a model that fills the chip in bf16 fits here in float32.
+Every matrix product runs at the highest precision.
 
 ``quant="fp8"`` selects the control: the same forward computed in a
 precision below bf16, every matrix-product operand (weights per output
@@ -27,10 +31,115 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import generator as G
 import weights as W
 
 HIGHEST = jax.lax.Precision.HIGHEST
 _F8_MAX = 448.0
+
+
+def program_config(m: dict):
+    """The program's ModelConfig for the configuration file ``m``."""
+    from repro.configs.base import ModelConfig
+    return ModelConfig(
+        name=m["name"], family="dense", num_layers=m["num_hidden_layers"],
+        d_model=m["hidden_size"], num_heads=m["num_attention_heads"],
+        num_kv_heads=m["num_key_value_heads"], head_dim=m["head_dim"],
+        d_ff=m["intermediate_size"], vocab_size=m["vocab_size"],
+        norm_eps=m["rms_norm_eps"], rope_theta=m["rope_theta"],
+        tie_embeddings=m["tie_word_embeddings"], source=m["source"])
+
+
+def num_layers(m: dict) -> int:
+    return m["num_hidden_layers"]
+
+
+def layer_shapes(m: dict) -> dict:
+    """One decoder layer's leaves in the program's layout."""
+    d, hq, hkv = m["hidden_size"], m["num_attention_heads"], \
+        m["num_key_value_heads"]
+    hd, ff = m["head_dim"], m["intermediate_size"]
+    norm = W.Leaf((d,), "ones")
+
+    def proj(shape, fan_in):
+        return W.Leaf(shape, "normal", fan_in)
+
+    return {"norm1": norm,
+            "attn": {"wq": proj((d, hq, hd), d), "wk": proj((d, hkv, hd), d),
+                     "wv": proj((d, hkv, hd), d),
+                     "wo": proj((hq, hd, d), hq * hd)},
+            "norm2": norm,
+            "mlp": {"gate": proj((d, ff), d), "up": proj((d, ff), d),
+                    "down": proj((ff, d), ff)}}
+
+
+def top_shapes(m: dict) -> dict:
+    """The leaves outside the layers: the embedding, the final norm and,
+    where the embeddings are not tied, the output head."""
+    d, v = m["hidden_size"], W.padded_vocab(m)
+    top = {"embed": W.Leaf((v, d), "embed"),
+           "final_norm": W.Leaf((d,), "ones")}
+    if not m["tie_word_embeddings"]:
+        top["lm_head"] = W.Leaf((d, v), "head")
+    return top
+
+
+def prompt_ids(req, m: dict, max_len: int):
+    return G.prompt_ids(req, m["vocab_size"], max_len)
+
+
+# -- operations and bytes (read through flops.py) -----------------------------
+
+def _dims(m: dict):
+    return (m["num_hidden_layers"], m["hidden_size"],
+            m["num_attention_heads"], m["num_key_value_heads"],
+            m["head_dim"], m["intermediate_size"], m["vocab_size"])
+
+
+def layer_matmul_params(m: dict) -> int:
+    """Weights one token multiplies in one layer (projections and MLP)."""
+    _, d, hq, hkv, hd, ff, _ = _dims(m)
+    return d * (hq + 2 * hkv) * hd + hq * hd * d + 3 * d * ff
+
+
+def decode_window(m: dict, k: int, rows: int, ctx: int, b: int = 2) -> dict:
+    """A fused decode window of ``k`` steps over ``rows`` active requests
+    whose contexts sum to ``ctx`` tokens when the window starts.  At step
+    ``i`` each row attends over its context plus ``i + 1`` tokens.
+
+    ``attn_*``: the paged decode attention kernel (all layers);
+    ``model_flops``: the whole step (projections, MLP, attention, head)."""
+    L, d, hq, hkv, hd, _, v = _dims(m)
+    span = k * ctx + rows * k * (k + 1) // 2     # sum of attended lengths
+    attn_flops = 4 * hq * hd * L * span
+    attn_bytes = L * (2 * hkv * hd * b * span + k * rows * 2 * hq * hd * b)
+    tokens = k * rows
+    model_flops = tokens * 2 * (L * layer_matmul_params(m) + d * v) \
+        + attn_flops
+    return {"attn_flops": attn_flops, "attn_bytes": attn_bytes,
+            "model_flops": model_flops, "tokens": tokens}
+
+
+def prefill_wave(m: dict, rows, b: int = 2) -> dict:
+    """An admission wave; ``rows`` are (suffix tokens run, cached prefix
+    tokens).  Suffix queries attend causally among themselves and to the
+    whole cached prefix, which the kernel reads from the page pool.
+
+    ``attn_*``: the prefix-prefill attention kernel (all layers);
+    ``model_flops``: the wave's useful work, with one logits row a row."""
+    L, d, hq, hkv, hd, _, v = _dims(m)
+    attn_flops = attn_bytes = model_flops = tokens = 0
+    for s, p in rows:
+        f = 2 * hq * hd * L * s * (2 * p + s + 1)
+        attn_flops += f
+        attn_bytes += L * b * hd * (2 * hkv * p + s * (2 * hq + 2 * hkv))
+        model_flops += 2 * s * L * layer_matmul_params(m) + 2 * d * v + f
+        tokens += s
+    return {"attn_flops": attn_flops, "attn_bytes": attn_bytes,
+            "model_flops": model_flops, "tokens": tokens}
+
+
+# -- the plain reference ------------------------------------------------------
 
 
 def _round_to(x, axes, quant):
@@ -107,19 +216,19 @@ def _prepare(lw, quant):
 @functools.partial(jax.jit, static_argnames=("m", "quant"))
 def _layer(lw, h, *, m, quant):
     f = _prepare(lw, quant)
-    return jax.lax.map(lambda row: _layer_one(f, row, dict(m), quant), h)
+    return jax.lax.map(lambda row: _layer_one(f, row, W.thawed(m), quant), h)
 
 
 @functools.partial(jax.jit, static_argnames=("m",))
 def _make_layer(key, layer, *, m):
-    return W.make_layer(key, layer, dict(m), jnp.bfloat16)
+    return W.make_layer(key, layer, layer_shapes(W.thawed(m)), jnp.bfloat16)
 
 
 @functools.partial(jax.jit, static_argnames=("m", "quant"))
 def _row_logits(h, head, final_norm, pos, *, m, quant):
     """One row's logits over the real vocabulary at positions ``pos``
     [T], from final hidden states ``h`` [S, d]."""
-    m = dict(m)
+    m = W.thawed(m)
     x = rms_norm(h, final_norm.astype(jnp.float32), m["rms_norm_eps"])
     x = _round_to(jnp.take(x, pos, axis=0), (1,), quant)
     hd = _round_to(head.astype(jnp.float32)[:, :m["vocab_size"]], (0,),
@@ -154,18 +263,13 @@ def _seq_arrays(seqs, bucket: int = 128):
     return toks, pos, served, valid
 
 
-def _frozen(m: dict) -> tuple:
-    return tuple(sorted((k, v) for k, v in m.items()
-                        if isinstance(v, (int, float, bool))))
-
-
 def hidden_states(seed: int, m: dict, toks, quant=None):
     """Final hidden states [R, S, d] of the reference (or of the control
     with ``quant``), layer by layer from weights made again from the
     seed."""
     key = W.seed_key(seed)
-    fm = _frozen(m)
-    emb = W.embed_table(key, m, jnp.bfloat16)
+    fm = W.frozen(m)
+    emb = W.top_leaf(key, top_shapes(m)["embed"], jnp.bfloat16)
     h = jnp.take(emb, jnp.asarray(toks), axis=0).astype(jnp.float32)
     del emb
     for layer in range(m["num_hidden_layers"]):
@@ -176,11 +280,12 @@ def hidden_states(seed: int, m: dict, toks, quant=None):
 
 def output_head(seed: int, m: dict):
     key = W.seed_key(seed)
-    if m["tie_word_embeddings"]:
-        head = W.embed_table(key, m, jnp.bfloat16).T
+    top = top_shapes(m)
+    if "lm_head" in top:
+        head = W.top_leaf(key, top["lm_head"], jnp.bfloat16)
     else:
-        head = W.head_table(key, m, jnp.bfloat16)
-    return head, jnp.ones((m["hidden_size"],), jnp.bfloat16)
+        head = W.top_leaf(key, top["embed"], jnp.bfloat16).T
+    return head, W.top_leaf(key, top["final_norm"], jnp.bfloat16)
 
 
 def served_gaps(seed: int, m: dict, seqs, control=None) -> dict:
@@ -192,7 +297,7 @@ def served_gaps(seed: int, m: dict, seqs, control=None) -> dict:
     ``control="fp8"``, the widest
     gap of the token the control's own forward puts first there."""
     toks, pos, served, valid = _seq_arrays(seqs)
-    fm = _frozen(m)
+    fm = W.frozen(m)
     head, fnorm = output_head(seed, m)
     h = hidden_states(seed, m, toks)
     hc = (hidden_states(seed, m, toks, quant=control)

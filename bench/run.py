@@ -43,8 +43,7 @@ import sys  # noqa: E402
 BENCH = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(BENCH)
 CACHE = os.path.join(ROOT, ".bench_cache")
-for p in (os.path.join(ROOT, "src"), os.path.join(BENCH, "traffic"),
-          os.path.join(BENCH, "reference"), BENCH):
+for p in (os.path.join(ROOT, "src"), os.path.join(BENCH, "traffic"), BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)
 
@@ -103,18 +102,6 @@ class CompileCount:
             self.seconds += secs
 
 
-def model_config(c: dict):
-    """The program's ModelConfig for the configuration file ``c``."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=c["name"], family="dense", num_layers=c["num_hidden_layers"],
-        d_model=c["hidden_size"], num_heads=c["num_attention_heads"],
-        num_kv_heads=c["num_key_value_heads"], head_dim=c["head_dim"],
-        d_ff=c["intermediate_size"], vocab_size=c["vocab_size"],
-        norm_eps=c["rms_norm_eps"], rope_theta=c["rope_theta"],
-        tie_embeddings=c["tie_word_embeddings"], source=c["source"])
-
-
 def pct(values, q: float) -> float:
     import numpy as np
     return float(np.percentile(np.asarray(values, np.float64), q))
@@ -125,6 +112,7 @@ def build(conf: dict, mix: dict, seed: int):
     import jax
     import jax.numpy as jnp
 
+    import arch
     import driver
     import generator as G
     import weights as W
@@ -136,7 +124,7 @@ def build(conf: dict, mix: dict, seed: int):
     from repro.serving.paged_cache import BlockAllocator, MispredictionEWMA
 
     sv = conf["serving"]
-    cfg = model_config(conf)
+    cfg = arch.of(conf).program_config(conf)
     dtype = jnp.dtype(sv["dtype"])
     params = W.make_params(seed, conf, dtype)
     jax.block_until_ready(params)
@@ -192,6 +180,7 @@ def serve(conf, mix, seed, seconds, trace_on, engine, svc, clock,
           id_base: int = 0):
     """Pre-roll, window and drain.  Returns the probe and run facts.
     ``id_base`` offsets request ids, for several serves on one engine."""
+    import arch
     import driver
     import generator as G
     from repro.core.types import Request
@@ -203,8 +192,8 @@ def serve(conf, mix, seed, seconds, trace_on, engine, svc, clock,
                       max_gen=sv["max_gen"], make=Request)
     for r in reqs:
         r.req_id += id_base
-    plen = {r.req_id: len(G.prompt_ids(r, conf["vocab_size"],
-                                       sv["max_len"])) for r in reqs}
+    prompt_ids = arch.of(conf).prompt_ids
+    plen = {r.req_id: len(prompt_ids(r, conf, sv["max_len"])) for r in reqs}
     t0 = time.perf_counter()
     window = (t0 + pre, t0 + pre + seconds)
     probe = driver.Probe(svc, reqs, t0=t0, window=window,
@@ -259,12 +248,12 @@ def check_served(conf, seed, generated, attempted, n_sample, control=None):
     """Run the reference over a sample of finished requests that count:
     the longest one, and the rest drawn from the seed.  Returns the widest
     gap, the number of served tokens compared and, with ``control`` (a
-    precision ``dense.served_gaps`` knows), the control's widest gap on
-    the same positions."""
+    precision the architecture's ``served_gaps`` knows), the control's
+    widest gap on the same positions."""
     import numpy as np
 
-    import dense
-    import generator as G
+    import arch
+    A = arch.of(conf)
     sv = conf["serving"]
     done = [r for r in attempted if r.req_id in generated]
     if not done:
@@ -274,9 +263,9 @@ def check_served(conf, seed, generated, attempted, n_sample, control=None):
     rng = np.random.default_rng([seed, 0xC4EC])
     pick = [longest] + [rest[i] for i in rng.choice(
         len(rest), size=min(n_sample - 1, len(rest)), replace=False)]
-    seqs = [(G.prompt_ids(r, conf["vocab_size"], sv["max_len"]),
-             generated[r.req_id]) for r in pick]
-    out = dense.served_gaps(seed, conf, seqs, control=control)
+    seqs = [(A.prompt_ids(r, conf, sv["max_len"]), generated[r.req_id])
+            for r in pick]
+    out = A.served_gaps(seed, conf, seqs, control=control)
     cgap = float(out["control_gap"].max()) if control else None
     return float(out["gap"].max()), out["tokens"], cgap
 

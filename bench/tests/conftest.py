@@ -7,7 +7,6 @@ import sys
 
 BENCH = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ROOT = os.path.dirname(BENCH)
-for p in (os.path.join(ROOT, "src"), os.path.join(BENCH, "traffic"),
-          os.path.join(BENCH, "reference"), BENCH):
+for p in (os.path.join(ROOT, "src"), os.path.join(BENCH, "traffic"), BENCH):
     if p not in sys.path:
         sys.path.insert(0, p)

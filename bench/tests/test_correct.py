@@ -6,7 +6,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-import dense
+import arch
 import generator as G
 import tiny
 import weights as W
@@ -73,6 +73,7 @@ def test_control_reads_above_the_program():
     bf16 engine served, reads at least three times the program's gap."""
     bench, w, conf, mix, peak, dev = tiny.cell()
     from repro.core.types import Request
+    A = arch.of(conf)
     sv = conf["serving"]
     seed = 11
     reqs = G.arrivals(mix, seed, [0.0, 2.0], max_len=sv["max_len"],
@@ -80,7 +81,7 @@ def test_control_reads_above_the_program():
     params = W.make_params(seed, conf, jnp.bfloat16)
     import run as RUN
     eng = E.PagedContinuousEngine(
-        RUN.model_config(conf), params, max_concurrency=sv["slots"],
+        A.program_config(conf), params, max_concurrency=sv["slots"],
         num_blocks=sv["num_blocks"], max_len=sv["max_len"],
         max_gen=sv["max_gen"], dtype=jnp.bfloat16)
     for r in reqs:
@@ -88,9 +89,9 @@ def test_control_reads_above_the_program():
     assert eng.join_many(reqs) == len(reqs)
     while eng.num_active:
         eng.step_window()
-    seqs = [(G.prompt_ids(r, conf["vocab_size"], sv["max_len"]),
-             eng.generated[r.req_id]) for r in reqs]
-    out = dense.served_gaps(seed, conf, seqs, control="fp8")
+    seqs = [(A.prompt_ids(r, conf, sv["max_len"]), eng.generated[r.req_id])
+            for r in reqs]
+    out = A.served_gaps(seed, conf, seqs, control="fp8")
     program, control = float(out["gap"].max()), float(
         out["control_gap"].max())
     assert control >= 3 * program, (program, control)

@@ -9,9 +9,13 @@ TINY_LIMIT = 0.02
 
 
 def cell(traffic: str = "lmaas-steady", rate: float = 10.0,
-         drain_cap_s: float = 60.0):
+         drain_cap_s: float = 60.0, reference: str = ""):
+    """The tiny cell; ``reference`` names another architecture module for
+    its configuration."""
     bench = RUN.load_json(RUN.ROOT, "BENCHMARK.json")
     conf = RUN.load_json(RUN.BENCH, "configs", "smollm-135m.json")
+    if reference:
+        conf["reference"] = reference
     conf.update(hidden_size=64, intermediate_size=128, num_attention_heads=4,
                 num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
                 vocab_size=512)

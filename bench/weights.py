@@ -6,20 +6,27 @@ function regenerates any one layer alone, bit for bit, so the plain
 reference can rebuild a model too large to hold twice, layer by layer,
 without taking anything the program holds.
 
-Scales: every projection is N(0, 1/fan_in), norms are ones, and the
-token embedding is N(0, EMBED_STD^2).  A small embedding keeps the
-current token's own row a small part of the final hidden state, so a
-tied head does not simply repeat the current token: greedy decoding then
-meets near-ties between tokens, which is what makes a comparison of
-logits sensitive to precision (at 0.3 every stream repeated one token;
-at 0.01 they vary, on smollm-135m in bf16).
+The layout comes from the configuration's architecture module
+(``arch.py``): a tree of ``Leaf`` for one layer and one for the leaves
+outside the layers, each with its first draw (projections N(0, 1/fan_in),
+norms ones, biases zeros).  How each leaf's values follow from the seed is
+shared by every architecture.  The token embedding is N(0, EMBED_STD^2).
+A small embedding keeps the current token's own row a small part of the
+final hidden state, so a tied head does not simply repeat the current
+token: greedy decoding then meets near-ties between tokens, which is what
+makes a comparison of logits sensitive to precision (at 0.3 every stream
+repeated one token; at 0.01 they vary, on smollm-135m in bf16).
 """
 from __future__ import annotations
 
 import functools
+import json
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+
+import arch
 
 EMBED_STD = 0.01
 _EMBED_CHUNKS = 16        # the vocabulary table is made in row chunks
@@ -34,38 +41,39 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(key, (seed >> 32) & 0xFFFFFFFF)
 
 
-def layer_shapes(m: dict) -> dict:
-    """Shapes of one decoder layer's leaves in the program's layout."""
-    d, hq, hkv = m["hidden_size"], m["num_attention_heads"], \
-        m["num_key_value_heads"]
-    hd, ff = m["head_dim"], m["intermediate_size"]
-    return {"norm1": (d,),
-            "attn": {"wq": (d, hq, hd), "wk": (d, hkv, hd),
-                     "wv": (d, hkv, hd), "wo": (hq, hd, d)},
-            "norm2": (d,),
-            "mlp": {"gate": (d, ff), "up": (d, ff), "down": (ff, d)}}
+class Leaf(NamedTuple):
+    """One parameter leaf: its shape and how it is first drawn.
+
+    ``init``: ``"normal"`` (N(0, 1/``fan_in``)), ``"ones"`` or ``"zeros"``;
+    outside the layers also ``"embed"`` (the token embedding,
+    [padded vocab, hidden]) and ``"head"`` (the untied output head,
+    [hidden, padded vocab])."""
+    shape: tuple
+    init: str
+    fan_in: int = 0
 
 
-def _fan_in(name: str, shape) -> int:
-    if name == "wo":
-        return shape[0] * shape[1]
-    return shape[0]
+def _fill(leaf: Leaf, dtype) -> jax.Array:
+    if leaf.init == "ones":
+        return jnp.ones(leaf.shape, dtype)
+    if leaf.init == "zeros":
+        return jnp.zeros(leaf.shape, dtype)
+    raise ValueError(f"unknown leaf init {leaf.init!r}")
 
 
-def make_layer(key: jax.Array, layer, m: dict, dtype) -> dict:
-    """Layer ``layer`` (a Python or traced int) of the model of sizes
-    ``m``; the same values wherever it is called from."""
+def make_layer(key: jax.Array, layer, shapes: dict, dtype) -> dict:
+    """Layer ``layer`` (a Python or traced int) of the layout ``shapes``
+    (a tree of ``Leaf``); the same values wherever it is called from."""
     lk = jax.random.fold_in(jax.random.fold_in(key, _TAG_LAYER), layer)
-    leaves, treedef = jax.tree_util.tree_flatten_with_path(
-        layer_shapes(m), is_leaf=lambda x: isinstance(x, tuple))
+    leaves, treedef = jax.tree_util.tree_flatten(
+        shapes, is_leaf=lambda x: isinstance(x, Leaf))
     out = []
-    for i, (path, shape) in enumerate(leaves):
-        name = path[-1].key
-        if name.startswith("norm"):
-            out.append(jnp.ones(shape, dtype))
+    for i, leaf in enumerate(leaves):
+        if leaf.init != "normal":
+            out.append(_fill(leaf, dtype))
             continue
-        std = _fan_in(name, shape) ** -0.5
-        w = jax.random.normal(jax.random.fold_in(lk, i), shape, dtype)
+        std = leaf.fan_in ** -0.5
+        w = jax.random.normal(jax.random.fold_in(lk, i), leaf.shape, dtype)
         out.append(w * jnp.asarray(std, dtype))
     return jax.tree_util.tree_unflatten(treedef, out)
 
@@ -92,37 +100,44 @@ def padded_vocab(m: dict) -> int:
     return -(-v // step) * step
 
 
-def embed_table(key, m: dict, dtype) -> jax.Array:
-    return _table(key, _TAG_EMBED, padded_vocab(m), m["hidden_size"],
-                  EMBED_STD, dtype)
-
-
-def head_table(key, m: dict, dtype) -> jax.Array:
-    """The untied output head, [hidden, padded vocab]."""
-    d = m["hidden_size"]
-    return _table(key, _TAG_HEAD, padded_vocab(m), d, d ** -0.5, dtype).T
+def top_leaf(key, leaf: Leaf, dtype) -> jax.Array:
+    """A leaf outside the layers, the same wherever it is made."""
+    if leaf.init == "embed":
+        return _table(key, _TAG_EMBED, *leaf.shape, EMBED_STD, dtype)
+    if leaf.init == "head":
+        d, v = leaf.shape
+        return _table(key, _TAG_HEAD, v, d, d ** -0.5, dtype).T
+    return _fill(leaf, dtype)
 
 
 def _make_params(key, m: dict, dtype) -> dict:
-    layers = jnp.arange(m["num_hidden_layers"])
-    params = {"embed": embed_table(key, m, dtype),
-              "blocks": jax.lax.map(
-                  lambda l: make_layer(key, l, m, dtype), layers),
-              "final_norm": jnp.ones((m["hidden_size"],), dtype)}
-    if not m["tie_word_embeddings"]:
-        params["lm_head"] = head_table(key, m, dtype)
+    A = arch.of(m)
+    shapes = A.layer_shapes(m)
+    params = {name: top_leaf(key, leaf, dtype)
+              for name, leaf in A.top_shapes(m).items()}
+    params["blocks"] = jax.lax.map(
+        lambda l: make_layer(key, l, shapes, dtype),
+        jnp.arange(A.num_layers(m)))
     return params
 
 
+def frozen(m: dict) -> str:
+    """The configuration, hashable (a static argument of a jitted call);
+    ``thawed`` gives it back whole, nested groups too."""
+    return json.dumps(m, sort_keys=True)
+
+
+def thawed(frozen_m: str) -> dict:
+    return json.loads(frozen_m)
+
+
 @functools.lru_cache(maxsize=None)
-def _compiled(frozen_sizes: tuple, dtype_name: str):
-    m = dict(frozen_sizes)
+def _compiled(frozen_m: str, dtype_name: str):
+    m = thawed(frozen_m)
     dtype = jnp.dtype(dtype_name)
     return jax.jit(lambda key: _make_params(key, m, dtype))
 
 
 def make_params(seed: int, m: dict, dtype) -> dict:
     """The whole model in one jitted call on the default device."""
-    sizes = tuple(sorted((k, v) for k, v in m.items()
-                         if isinstance(v, (int, float, bool))))
-    return _compiled(sizes, jnp.dtype(dtype).name)(seed_key(seed))
+    return _compiled(frozen(m), jnp.dtype(dtype).name)(seed_key(seed))
